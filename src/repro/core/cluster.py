@@ -118,15 +118,13 @@ _jax_kernel = _JAX_UNRESOLVED
 
 def _resolve_jax_fid_slots():
     """The accelerator twin of ``fid_slots`` when the deployment opts
-    in (``REPRO_JAX_ROUTING=1``) and jax imports; None otherwise.  The
-    numpy path stays the default: on a CPU-only coordinator the jit
-    round-trip costs more than the mix."""
+    in (``REPRO_JAX_ROUTING=1``); None otherwise.  The numpy path stays
+    the default: on a CPU-only coordinator the jit round-trip costs more
+    than the mix.  A deployment that opted in and cannot import the
+    twin gets the ImportError, not numpy routing in its place."""
     if os.environ.get("REPRO_JAX_ROUTING") != "1":
         return None
-    try:
-        from ..kernels import stream_ops
-    except Exception:
-        return None
+    from ..kernels import stream_ops
     return stream_ops.fid_slots
 
 

@@ -21,11 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the pinned JAX names this TPUCompilerParams; newer releases renamed it
-# to CompilerParams — accept either
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or \
-    getattr(pltpu, "CompilerParams")
-
 NEG_INF = -1e30
 
 
@@ -83,7 +78,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_bhsd(q, k, v, *, causal=True, window=0, cap=0.0,
                          scale=None, block_q=512, block_k=512,
-                         seq_q=None, seq_k=None, interpret=True):
+                         seq_q=None, seq_k=None, interpret=False):
     """q: (BH, Sq, D); k, v: (BKV, Sk, D) with BH = B*H, BKV = B*KV.
     Sq/Sk/D must already be padded to block/lane multiples by the caller;
     ``seq_q``/``seq_k`` give the pre-padding logical lengths.
@@ -120,7 +115,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0, cap=0.0,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -2,8 +2,9 @@
 
 Handles: GQA head folding, padding of sequence lengths to block
 multiples and head_dim to the 128-lane MXU width, and the
-models.layers-compatible calling convention.  ``interpret=True``
-(default off-TPU) runs the kernel body in Python for validation.
+models.layers-compatible calling convention.  ``interpret=True`` runs
+the kernel body in Python for validation off the TPU; callers ask for
+it explicitly.
 """
 
 from __future__ import annotations
@@ -16,25 +17,16 @@ import jax.numpy as jnp
 from .flash_attention import flash_attention_bhsd
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
-
-
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "cap", "scale", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, q_pos=None, k_pos=None, *, causal=True,
                     window=0, cap=0.0, scale=None, block_q=512,
-                    block_k=512, interpret=None, **_ignored):
+                    block_k=512, interpret=False, **_ignored):
     """q: (B,Sq,H,D); k,v: (B,Sk,KV,D) -> (B,Sq,H,D).
 
     Positions are assumed contiguous from 0 (training/prefill layout);
     the q_pos/k_pos arguments exist for signature compatibility with
     ``models.layers.attention_core``."""
-    if interpret is None:
-        interpret = not _on_tpu()
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     scale = scale if scale is not None else D ** -0.5

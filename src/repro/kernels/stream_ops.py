@@ -15,11 +15,11 @@ Only the low 64 bits of each product are needed, which keeps the limb
 algebra to one full 32x32 product plus two wrapping cross terms.
 
 ``fid_slots`` is the host-callable wrapper (numpy in, numpy out).
-``fid_slots_pallas`` routes the same mix through a ``pallas_call``
-elementwise kernel (VMEM-resident, interpret mode off-TPU) — the
-fusion-friendly form for TPU deployments.  Both are gated behind
-``REPRO_JAX_ROUTING=1`` in ``cluster.batch_slots``; the numpy path
-stays the production default on CPU hosts.
+``fid_slots_pallas`` routes the same mix through a tiled ``pallas_call``
+elementwise kernel — the fusion-friendly form for TPU deployments;
+``interpret=True`` runs its body in Python off the TPU.  The device
+twin is used by ``cluster.batch_slots`` under ``REPRO_JAX_ROUTING=1``;
+the numpy path stays the default on CPU hosts.
 """
 
 from __future__ import annotations
@@ -116,27 +116,52 @@ def fid_slots(seq, oid, ver, n_slots: int = 64) -> np.ndarray:
 
 
 # -- Pallas form -----------------------------------------------------------
+#: the kernel sees the columns as (rows, 128) lane-dense tiles; one grid
+#: step covers _TILE_ROWS rows (32768 records, 640 KiB of VMEM for the
+#: four inputs and the output), so any record count compiles
+_LANES = 128
+_TILE_ROWS = 256
+
+
 def _slots_kernel(seq_hi_ref, seq_lo_ref, oid_ref, ver_ref, out_ref,
                   *, n_slots):
-    zh, zl = _seed64(seq_hi_ref[:], seq_lo_ref[:], oid_ref[:], ver_ref[:])
-    out_ref[:] = _mix64(zh, zl, n_slots)
+    zh, zl = _seed64(seq_hi_ref[...], seq_lo_ref[...], oid_ref[...],
+                     ver_ref[...])
+    out_ref[...] = _mix64(zh, zl, n_slots)
+
+
+@functools.partial(jax.jit, static_argnames=("n_slots", "interpret"))
+def _fid_slots_tiled(seq_hi, seq_lo, oid, ver, n_slots, interpret=False):
+    """The mix over 1-D uint32 columns as a 1-D grid of row tiles; the
+    columns are zero-padded to whole tiles and the pad rows dropped."""
+    from jax.experimental import pallas as pl
+
+    n = seq_lo.shape[0]
+    rows = -(-max(n, 1) // _LANES)
+    rows = -(-rows // 8) * 8                     # whole (8, 128) vreg tiles
+    tile = min(_TILE_ROWS, rows)
+    rows = -(-rows // tile) * tile
+    cols = [jnp.pad(c, (0, rows * _LANES - n)).reshape(rows, _LANES)
+            for c in (seq_hi, seq_lo, oid, ver)]
+    spec = pl.BlockSpec((tile, _LANES), lambda i: (i, 0))
+    out = pl.pallas_call(
+        functools.partial(_slots_kernel, n_slots=n_slots),
+        grid=(rows // tile,),
+        in_specs=[spec] * 4,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.uint32),
+        interpret=interpret,
+    )(*cols)
+    return out.reshape(-1)[:n]
 
 
 def fid_slots_pallas(seq, oid, ver, n_slots: int = 64,
-                     interpret: bool = True) -> np.ndarray:
-    """The same mix as one elementwise ``pallas_call`` (VMEM in/out).
-
-    Interpret mode (the off-TPU default) runs the kernel body in
-    Python — used by the equivalence tests; on TPU the kernel is a
-    single VPU pass over the routing columns."""
-    from jax.experimental import pallas as pl
-
+                     interpret: bool = False) -> np.ndarray:
+    """The same mix as a tiled elementwise ``pallas_call``: one VPU
+    pass over the routing columns on the TPU.  ``interpret=True`` runs
+    the kernel body in Python (the equivalence tests off the TPU)."""
     if not 0 < n_slots < _MAX_SLOTS:
         raise ValueError(f"n_slots must be in (0, {_MAX_SLOTS})")
-    seq_hi, seq_lo, oid, ver = _as_pairs(seq, oid, ver)
-    out = pl.pallas_call(
-        functools.partial(_slots_kernel, n_slots=int(n_slots)),
-        out_shape=jax.ShapeDtypeStruct(seq_lo.shape, jnp.uint32),
-        interpret=interpret,
-    )(seq_hi, seq_lo, oid, ver)
+    out = _fid_slots_tiled(*_as_pairs(seq, oid, ver), n_slots=int(n_slots),
+                           interpret=interpret)
     return np.asarray(out).astype(np.int64)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Two artifacts per cell:
@@ -39,6 +36,7 @@ Usage:
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -87,8 +85,6 @@ def _compile_and_measure(cfg, shape, rules, mesh, n_micro,
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # older jax: one dict per program
-        cost = cost[0] if cost else {}
     per_coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -285,6 +281,11 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
 
 
 def main() -> int:
+    # a CPU compile tool by design: 512 host devices stand in for the
+    # production meshes; set here, before jax initializes, so importing
+    # this module changes nobody's devices
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

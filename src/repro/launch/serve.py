@@ -37,7 +37,9 @@ def main() -> int:
     from ..models import transformer as T
     from ..track import ActivityTracker, CacheInvalidator
     from ..runtime.steps import build_decode_step, build_prefill_step
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = C.get_smoke(args.arch) if args.smoke else C.get_config(args.arch)
     params = T.init_params(cfg, seed=0)
     B, P, G = args.batch, args.prompt_len, args.gen_len

@@ -38,7 +38,9 @@ def main() -> int:
 
     from .. import configs as C
     from ..runtime.train_loop import Trainer
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = C.get_smoke(args.arch) if args.smoke else C.get_config(args.arch)
     trainer = Trainer(cfg, workdir=args.workdir,
                       global_batch=args.global_batch, seq_len=args.seq_len,

@@ -108,8 +108,12 @@ def ssd_scan(xh, dt, A, Bm, Cm, chunk: int,
         # intra-chunk (attention-like, per head through its group)
         CB = jnp.einsum("bqgn,bkgn->bgqk", C_c.astype(jnp.float32),
                         B_c.astype(jnp.float32))            # (B,G,Q,Q)
-        Ldec = jnp.exp(cum[:, :, None, :] - cum[:, None, :, :])  # (B,Q,K,H)
-        Ldec = jnp.where(causal[None, :, :, None], Ldec, 0.0)
+        # mask the exponent, not the exponential: above the diagonal the
+        # difference is positive and overflows over a long chunk, and
+        # where(mask, inf, 0) back-propagates 0 * inf = NaN
+        seg = jnp.where(causal[None, :, :, None],
+                        cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
+        Ldec = jnp.exp(seg)                                 # (B,Q,K,H)
         CBh = jnp.repeat(CB, hpg, axis=1)                   # (B,H,Q,K)
         scores = CBh.transpose(0, 2, 3, 1) * Ldec * dt_c[:, None, :, :]
         y_diag = jnp.einsum("bqkh,bkhp->bqhp", scores,

@@ -14,6 +14,7 @@ derive random init, abstract ShapeDtypeStructs (dry-run) and shardings.
 from __future__ import annotations
 
 import math
+import zlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -107,8 +108,10 @@ def init_params(cfg: ModelConfig, seed: int = 0,
 
     def init(path, leaf):
         shape, axes, std = leaf
+        # crc32, not hash(): str hashes are salted per process, and the
+        # same seed must give the same weights in every process
         key = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                 abs(hash(path)) % (1 << 31))
+                                 zlib.crc32(path.encode()) % (1 << 31))
         if std == 0.0:
             x = jnp.zeros(shape, dtype)
             if path.endswith("A_log"):

@@ -40,16 +40,24 @@ def make_elastic_mesh(n_devices: Optional[int] = None):
     return jax.sharding.Mesh(grid, ("data", "model"))
 
 
+def state_shardings(cfg, rules: LogicalRules):
+    """(param shardings, AdamW-state shardings) under ``rules``: m/v
+    shard exactly like the params, the step counter is replicated."""
+    p_sh = shardings_of(rules, T.param_axes(cfg))
+    o_sh = adamw.AdamWState(
+        step=jax.sharding.NamedSharding(rules.mesh,
+                                        jax.sharding.PartitionSpec()),
+        m=p_sh, v=p_sh)
+    return p_sh, o_sh
+
+
 def reshard_state(cfg, params, opt_state, mesh,
                   overrides: Optional[Dict] = None):
     """Land host (numpy) param/opt trees on ``mesh`` with the logical
     rules — the elastic restore path."""
     rules = LogicalRules(mesh, overrides)
-    p_sh = shardings_of(rules, T.param_axes(cfg))
+    p_sh, o_sh = state_shardings(cfg, rules)
     params = jax.tree.map(jax.device_put, params, p_sh)
     if opt_state is not None:
-        o_sh = adamw.AdamWState(
-            step=jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()),
-            m=p_sh, v=p_sh)
         opt_state = jax.tree.map(jax.device_put, opt_state, o_sh)
     return params, opt_state, rules

@@ -15,6 +15,7 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import configs as C
 from ..checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
@@ -24,9 +25,8 @@ from ..models import transformer as T
 from ..optim import adamw
 from ..track import (ActivityTracker, CheckpointCommitter, MetricsDB,
                      StragglerDetector)
-from .elastic import make_elastic_mesh, reshard_state
+from .elastic import make_elastic_mesh, reshard_state, state_shardings
 from .sharding import LogicalRules, use_rules
-from .specs import shardings_of
 from .steps import TrainHParams, build_train_step
 
 
@@ -69,18 +69,25 @@ class Trainer:
             tracker=t) for h, t in enumerate(self.trackers)]
 
         # --- model/optimizer state ------------------------------------------
+        # params and AdamW state are created in their shardings by one
+        # jitted init, so no whole copy of either lands on one device
         self.rules = LogicalRules(self.mesh)
-        with use_rules(self.rules):
+        p_sh, o_sh = state_shardings(cfg, self.rules)
+
+        def init_state():
             params = T.init_params(cfg, seed=seed)
-            opt = adamw.init(params)
-        p_sh = shardings_of(self.rules, T.param_axes(cfg))
-        self.params = jax.tree.map(jax.device_put, params, p_sh)
-        self.opt_state = opt
+            return params, adamw.init(params)
+
+        with use_rules(self.rules):
+            self.params, self.opt_state = jax.jit(
+                init_state, out_shardings=(p_sh, o_sh))()
         self.step = 0
         self._maybe_restore()
 
-        self.train_step = jax.jit(build_train_step(cfg, self.hp),
-                                  donate_argnums=(0, 1))
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+        self.train_step = jax.jit(
+            build_train_step(cfg, self.hp), donate_argnums=(0, 1),
+            out_shardings=(p_sh, o_sh, replicated))
         self.history: List[Dict[str, float]] = []
 
     # ------------------------------------------------------------------ io
@@ -115,8 +122,8 @@ class Trainer:
                          for k in shards[0]}
                 self.params, self.opt_state, metrics = self.train_step(
                     self.params, self.opt_state, batch)
+                loss = float(metrics["loss"])    # waits for the step
                 dt = time.time() - t0
-                loss = float(metrics["loss"])
                 self.step += 1
                 for t in self.trackers:
                     t.step_commit(self.step, loss, dt,

@@ -14,8 +14,11 @@ import pytest
 from repro import configs as C
 from repro.checkpoint import (AsyncCheckpointer, latest_step,
                               restore_checkpoint, save_checkpoint)
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.optim import adamw
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -52,7 +55,7 @@ def test_restore_with_mesh_shardings(tmp_path, small_state):
     cfg, tree = small_state
     save_checkpoint(tree, 3, str(tmp_path), n_shards=2)
     out = restore_checkpoint(tree, 3, str(tmp_path))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     params, opt, rules = reshard_state(cfg, out["params"], out["opt"], mesh)
     leaf = jax.tree.leaves(params)[0]
     assert leaf.sharding.mesh.shape == {"data": 1, "model": 1}
@@ -95,10 +98,10 @@ def test_crash_restart_resumes_exactly(tmp_path):
     env = dict(os.environ, PYTHONPATH="src")
     wd = str(tmp_path / "run")
     r1 = subprocess.run([sys.executable, "-c", script, "first", wd],
-                        capture_output=True, text=True, env=env, cwd="/root/repo")
+                        capture_output=True, text=True, env=env, cwd=REPO_ROOT)
     assert r1.returncode == 0, r1.stderr[-2000:]
     assert '"end": 4' in r1.stdout
     r2 = subprocess.run([sys.executable, "-c", script, "second", wd],
-                        capture_output=True, text=True, env=env, cwd="/root/repo")
+                        capture_output=True, text=True, env=env, cwd=REPO_ROOT)
     assert r2.returncode == 0, r2.stderr[-2000:]
     assert '"start": 4' in r2.stdout and '"end": 5' in r2.stdout

@@ -136,8 +136,37 @@ def test_jax_fid_slots_matches_scalar():
     for n_slots in (3, 64, 65535):
         want = [fid_slot(k, n_slots) for k in keys]
         assert stream_ops.fid_slots(seq, oid, ver, n_slots).tolist() == want
-        assert stream_ops.fid_slots_pallas(seq, oid, ver,
-                                           n_slots).tolist() == want
+        assert stream_ops.fid_slots_pallas(seq, oid, ver, n_slots,
+                                           interpret=True).tolist() == want
+
+
+@pytest.mark.parametrize("n", [1, 1000, 32768, 32768 * 2 + 5])
+def test_tiled_pallas_fid_slots_matches_numpy(n):
+    """Pad rows and tile edges of the gridded kernel drop out: every
+    record count gives numpy's slots, in order."""
+    stream_ops = pytest.importorskip("repro.kernels.stream_ops")
+    rng = np.random.default_rng(n)
+    seq = rng.integers(0, 1 << 64, n, dtype=np.uint64)
+    oid = rng.integers(0, 1 << 32, n, dtype=np.uint32)
+    ver = rng.integers(0, 1 << 32, n, dtype=np.uint32)
+    got = stream_ops.fid_slots_pallas(seq, oid, ver, 97, interpret=True)
+    np.testing.assert_array_equal(got, fid_slots(seq, oid, ver, 97))
+
+
+def test_jax_routing_opt_in_raises_when_twin_cannot_import(monkeypatch):
+    """With REPRO_JAX_ROUTING=1 the device twin is used, and a failed
+    import of it is an error, never a silent switch to numpy."""
+    import sys
+
+    import repro.kernels
+    from repro.core import cluster as cluster_mod
+    stream_ops = pytest.importorskip("repro.kernels.stream_ops")
+    monkeypatch.setenv("REPRO_JAX_ROUTING", "1")
+    assert cluster_mod._resolve_jax_fid_slots() is stream_ops.fid_slots
+    monkeypatch.delattr(repro.kernels, "stream_ops")
+    monkeypatch.setitem(sys.modules, "repro.kernels.stream_ops", None)
+    with pytest.raises(ImportError):
+        cluster_mod._resolve_jax_fid_slots()
 
 
 # --------------------------------------------------------------- project

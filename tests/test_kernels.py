@@ -98,7 +98,7 @@ def test_flash_integrates_with_attention_core():
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     naive = attention_core(q, k, v, pos, pos, impl="naive", causal=True)
     pall = attention_core(q, k, v, pos, pos, impl="pallas", causal=True,
-                          window=0, cap=0.0)
+                          window=0, cap=0.0, interpret=True)
     np.testing.assert_allclose(np.asarray(pall), np.asarray(naive),
                                rtol=2e-5, atol=2e-5)
 

@@ -2,6 +2,10 @@
 forward + one train-grad step + prefill/decode consistency on CPU.
 Asserts output shapes and absence of NaNs (assignment requirement)."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -162,3 +166,23 @@ def test_ring_buffer_window_cache_multi_step():
         np.testing.assert_allclose(
             np.asarray(step_logits[:, 0]), np.asarray(full_logits[:, t]),
             rtol=0.15, atol=0.15, err_msg=f"step {t}")
+
+
+def test_init_params_same_in_every_process():
+    """A seed gives the same weights in every process: the per-leaf key
+    must not come from Python's salted ``hash``."""
+    script = ("from repro import configs as C\n"
+              "from repro.models import init_params\n"
+              "import jax, numpy as np\n"
+              "p = init_params(C.get_smoke('mamba2-780m'), seed=0)\n"
+              "print(sum(float(np.abs(np.asarray(x, np.float64)).sum())\n"
+              "          for x in jax.tree.leaves(p)).hex())\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sums = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, PYTHONPATH="src", PYTHONHASHSEED=hash_seed)
+        r = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                           text=True, env=env, cwd=root)
+        assert r.returncode == 0, r.stderr[-3000:]
+        sums.append(r.stdout.split()[-1])
+    assert sums[0] == sums[1]

@@ -14,6 +14,8 @@ import pytest
 
 from repro.optim import adamw
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def quad_loss(p):
     return jnp.sum((p["w"] - 3.0) ** 2) + jnp.sum((p["b"] + 1.0) ** 2)
@@ -55,23 +57,18 @@ def test_compressed_psum_matches_exact_within_tolerance():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_host_mesh
         from repro.optim.compress import compressed_psum, plain_psum_mean
 
-        if hasattr(jax, "shard_map"):                # jax >= 0.5
-            shard_map, replication_kw = jax.shard_map, {"check_vma": False}
-        else:
-            from jax.experimental.shard_map import shard_map
-            replication_kw = {"check_rep": False}
-
-        mesh = jax.make_mesh((4,), ("dp",))
+        mesh = make_host_mesh(4, 1)                  # DP over "data"
         key = jax.random.PRNGKey(0)
         g_global = jax.random.normal(key, (4, 64))   # per-device grads
 
-        @partial(shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                 out_specs=(P("dp"), P("dp")), **replication_kw)
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+                 out_specs=(P("data"), P("data")), check_vma=False)
         def step(g, e):
-            gq, e = compressed_psum({"g": g}, {"g": e}, "dp")
+            gq, e = compressed_psum({"g": g}, {"g": e}, "data")
             return gq["g"], e["g"]
 
         exact = np.asarray(g_global.mean(0))
@@ -89,7 +86,7 @@ def test_compressed_psum_matches_exact_within_tolerance():
     """)
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, env=env, cwd="/root/repo")
+                       text=True, env=env, cwd=REPO_ROOT)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout
 

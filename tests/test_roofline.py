@@ -8,6 +8,8 @@ import subprocess
 import sys
 import textwrap
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_probe_extrapolation_matches_unrolled_compile():
     script = textwrap.dedent("""
@@ -16,6 +18,7 @@ def test_probe_extrapolation_matches_unrolled_compile():
         import dataclasses, json
         import jax
         from repro import configs as C
+        from repro.launch.mesh import make_host_mesh
         from repro.models import layers as ML, ssd as MS, transformer as T
         from repro.models.config import ShapeConfig
         from repro.runtime import specs as SP
@@ -25,7 +28,7 @@ def test_probe_extrapolation_matches_unrolled_compile():
                                          solve_probe_model)
 
         cfg = C.get_smoke("granite-8b").replace(n_layers=5)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_host_mesh(2, 2)
         shape = ShapeConfig("t", seq_len=32, global_batch=8, kind="train")
         rules = SP.cell_rules(cfg, shape, mesh)
         dp = 2
@@ -67,6 +70,6 @@ def test_probe_extrapolation_matches_unrolled_compile():
     """)
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, env=env, cwd="/root/repo")
+                       text=True, env=env, cwd=REPO_ROOT)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "VALIDATED" in r.stdout, r.stdout

@@ -11,11 +11,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_host_mesh
 from repro.runtime.sharding import DEFAULT_RULES, LogicalRules
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_rules_spec_no_duplicate_mesh_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     rules = LogicalRules(mesh)
     spec = rules.spec(("vocab", "mlp"))     # both map to "model"
     assert list(spec) == ["model", None]    # second use dropped
@@ -37,6 +40,7 @@ def test_sharded_train_step_runs_on_host_mesh():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np, dataclasses
         from repro import configs as C
+        from repro.launch.mesh import make_host_mesh
         from repro.models.config import ShapeConfig
         from repro.runtime import specs as SP
         from repro.runtime.sharding import use_rules
@@ -46,7 +50,7 @@ def test_sharded_train_step_runs_on_host_mesh():
 
         cfg = C.get_smoke("qwen2.5-14b")   # qkv-bias + non-div heads
         shape = ShapeConfig("t", seq_len=16, global_batch=4, kind="train")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_host_mesh(2, 2)
         rules = SP.cell_rules(cfg, shape, mesh)
         with use_rules(rules):
             step = build_train_step(cfg, TrainHParams(n_micro=2,
@@ -72,7 +76,7 @@ def test_sharded_train_step_runs_on_host_mesh():
     """)
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, env=env, cwd="/root/repo")
+                       text=True, env=env, cwd=REPO_ROOT)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "LOSS" in r.stdout
 
@@ -85,6 +89,7 @@ def test_sharded_equals_unsharded_loss():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from repro import configs as C
+        from repro.launch.mesh import make_host_mesh
         from repro.models.config import ShapeConfig
         from repro.runtime import specs as SP
         from repro.runtime.sharding import use_rules
@@ -99,7 +104,7 @@ def test_sharded_equals_unsharded_loss():
 
         ref, _ = jax.jit(lambda p: T.loss_fn(p, cfg, tokens, labels))(params)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_host_mesh(2, 2)
         shape = ShapeConfig("t", 16, 4, "train")
         rules = SP.cell_rules(cfg, shape, mesh)
         with use_rules(rules), mesh:
@@ -109,7 +114,7 @@ def test_sharded_equals_unsharded_loss():
     """)
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, env=env, cwd="/root/repo")
+                       text=True, env=env, cwd=REPO_ROOT)
     assert r.returncode == 0, r.stderr[-3000:]
 
 
@@ -189,3 +194,25 @@ def test_ssd_scan_matches_sequential_reference():
                 ys[b, t, h] = state[b, h] @ np.asarray(Cm[b, t, g])
     np.testing.assert_allclose(np.asarray(y), ys, rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(fin), state, rtol=2e-3, atol=2e-3)
+
+
+def test_ssd_scan_gradients_finite_over_a_long_chunk():
+    """mamba2-780m's 256-token chunk: the decay exponent above the
+    diagonal reaches hundreds, which overflows exp in f32; masking it
+    must not turn the backward pass into NaN."""
+    from repro.models.ssd import ssd_scan
+    B, S, H, P, G, N = 1, 256, 2, 4, 1, 4
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    xh = jax.random.normal(ks[0], (B, S, H, P))
+    Bm = jax.random.normal(ks[1], (B, S, G, N))
+    Cm = jax.random.normal(ks[2], (B, S, G, N))
+    dt = jnp.full((B, S, H), 0.7)
+    A = -jnp.array([1.0, 8.0])          # the A_log init's range
+
+    def loss(xh, dt, A, Bm, Cm):
+        y, fin = ssd_scan(xh, dt, A, Bm, Cm, chunk=256)
+        return jnp.sum(y) + jnp.sum(fin)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(xh, dt, A, Bm, Cm)
+    for g in grads:
+        assert bool(jnp.isfinite(g).all())
