@@ -61,10 +61,12 @@ from __future__ import annotations
 import itertools
 import os
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from ..obs.spans import TRACER
 from . import records as R
 from .errors import ClusterError
 from .history import JournalReplayReader
@@ -148,9 +150,11 @@ def batch_slots(batch: "R.RecordBatch",
     decoded header columns."""
     seq, oid, ver = batch.tfid_cols()
     kernel = _jax_fid_slots()
-    if kernel is not None and n_slots < (1 << 16):
-        return kernel(seq, oid, ver, n_slots)
-    return fid_slots(seq, oid, ver, n_slots)
+    with TRACER.span("cluster.route.slots") as span:
+        span.count = len(seq)
+        if kernel is not None and n_slots < (1 << 16):
+            return kernel(seq, oid, ver, n_slots)
+        return fid_slots(seq, oid, ver, n_slots)
 
 
 class ClusterReplayReader:
@@ -497,8 +501,22 @@ class LcapCluster:
         of offered; when the parking buffer is full the round stops
         reading (backpressure) until the migration settles.
         Returns ``(records routed, remote shards whose offer replies
-        already piggybacked their watermarks this round)``."""
+        already piggybacked their watermarks this round)``.
+
+        The round is span ``cluster.route`` (records routed), its offer
+        burst ``cluster.offer``; the records read write one
+        ``journal.wait``: how long they sat in the journals since their
+        ``cr_time`` (the MDT's wall clock), on the mean."""
+        with TRACER.span("cluster.route") as span:
+            n, covered = self._route_pass()
+            span.count = n
+        return n, covered
+
+    def _route_pass(self) -> Tuple[int, List[int]]:
         n = 0
+        # journal.wait: records read, and Σ (wall time read - cr_time)
+        waits = TRACER.enabled
+        aged = 0.0
         offers: List[List[Tuple[str, R.RecordBatch, int]]] = \
             [[] for _ in self.shards]
         owner_arr = self.routing.owner_array()
@@ -515,6 +533,9 @@ class LcapCluster:
                 hi = batch.packed_index(got - 1)
                 self.cursors[pid] = hi + 1
                 slots = batch_slots(batch, self.n_slots)
+                if waits:
+                    age = time.time_ns() - batch.times_np().astype(np.int64)
+                    aged += float(age.sum(dtype=np.float64))
                 if drain is not None and bool(drain[slots].any()):
                     dmask = drain[slots]
                     parked_rows = np.flatnonzero(dmask)
@@ -535,9 +556,21 @@ class LcapCluster:
                 n += got
                 if got < self.batch_size:
                     break
-        # two-phase: fire every shard's burst first, then drain the
-        # replies — the shards ingest their shares concurrently instead
-        # of the coordinator serializing on one shard at a time
+        if waits and n:
+            now = time.perf_counter_ns()
+            TRACER.record("journal.wait", now - int(aged / n), now, n)
+        with TRACER.span("cluster.offer") as span:
+            covered = self._offer(offers)
+            span.count = n
+        self.stats["routed"] += n
+        self.stats["routing_rounds"] += 1
+        return n, covered
+
+    def _offer(self, offers) -> List[int]:
+        """Two-phase: fire every shard's burst first, then drain the
+        replies — the shards ingest their shares concurrently instead
+        of the coordinator serializing on one shard at a time.  Returns
+        the remote shards whose replies carried their watermarks."""
         sent = []
         for i, shard_offers in enumerate(offers):
             if shard_offers and self.alive[i]:
@@ -552,9 +585,7 @@ class LcapCluster:
                     self.shard_acked[i].update(wm)
                     if getattr(self.shards[i], "remote", False):
                         covered.append(i)
-        self.stats["routed"] += n
-        self.stats["routing_rounds"] += 1
-        return n, covered
+        return covered
 
     def _shard_call(self, i: int, fn, *args):
         """Invoke a shard operation; a dead connection — or a shard
@@ -570,17 +601,21 @@ class LcapCluster:
     def pump(self, pump_shards: bool = True) -> int:
         """One routing round; with ``pump_shards`` (in-process shards)
         also one dispatch cycle per shard, then collective-ack
-        propagation."""
-        with self._lock:
+        propagation: span ``cluster.round`` over span ``cluster.route``,
+        the shards' ``proxy.pump`` and span ``cluster.ack``."""
+        with TRACER.span("cluster.round") as span, self._lock:
             moved, covered = self._route()
+            span.count = moved
             if pump_shards:
                 for i, shard in enumerate(self.shards):
                     if self.alive[i]:
                         got = self._shard_call(i, shard.pump)
                         moved += got or 0
-                self._collect_watermarks(skip=covered)
-            self._advance_migration_locked()
-            self._ack_journals()
+            with TRACER.span("cluster.ack"):
+                if pump_shards:
+                    self._collect_watermarks(skip=covered)
+                self._advance_migration_locked()
+                self._ack_journals()
             return moved
 
     # ------------------------------------------------ elastic operations
@@ -833,6 +868,7 @@ class LcapCluster:
         ``metrics`` wire verb and merged by :meth:`metrics`."""
         self._obs = registry
         registry.register_collector(self._collect_samples)
+        TRACER.attach_registry(registry)
         for i, shard in enumerate(self.shards):
             proxy = getattr(shard, "proxy", None)
             if proxy is not None:

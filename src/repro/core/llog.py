@@ -46,6 +46,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs.spans import TRACER
 from . import records as R
 
 _LEN = struct.Struct("<I")
@@ -320,13 +321,14 @@ class Llog:
         """Append many records under one lock acquisition; returns the
         indices of the records actually logged."""
         out: List[int] = []
-        with self._lock:
+        with TRACER.span("journal.append") as span, self._lock:
             if not self._readers:
                 return out
             for rec in recs:
                 idx = self._log_locked(rec)
                 if idx is not None:
                     out.append(idx)
+            span.count = len(out)
         return out
 
     # -- consuming -----------------------------------------------------------

@@ -60,10 +60,12 @@ import operator
 import threading
 import time
 from collections import deque
+from time import perf_counter_ns
 from typing import (Callable, Deque, Dict, Iterable, List, Optional, Tuple)
 
 import numpy as np
 
+from ..obs.spans import TRACER
 from . import records as R
 from .ack import AckTracker
 from .errors import (SubscriptionError, TenantError, UnknownConsumerError,
@@ -124,13 +126,19 @@ class _Outbox:
     enqueues and drains in O(1) and ``fetch_batches`` hands its rows
     out as a view, so the steady state never touches individual
     records.  ``len()`` counts *records*, matching the old deque of
-    tuples that backpressure caps are written against."""
+    tuples that backpressure caps are written against.
 
-    __slots__ = ("_q", "_n")
+    Every record carries the ``perf_counter_ns`` time it entered, kept
+    as runs ``[stamp, records]`` in queue order (a chunk is one run, a
+    dispatch pass's singles share one), so that a fetch can say how
+    long the records it takes waited (``take_stamps``)."""
+
+    __slots__ = ("_q", "_n", "_runs")
 
     def __init__(self):
         self._q: Deque = deque()
         self._n = 0
+        self._runs: Deque[List[int]] = deque()
 
     def __len__(self) -> int:
         return self._n
@@ -138,14 +146,36 @@ class _Outbox:
     def __bool__(self) -> bool:
         return self._n > 0
 
-    def append(self, item: Tuple[str, int, bytes]) -> None:
+    def append(self, item: Tuple[str, int, bytes], stamp: int) -> None:
         self._q.append(item)
         self._n += 1
+        runs = self._runs
+        if runs and runs[-1][0] == stamp:
+            runs[-1][1] += 1
+        else:
+            runs.append([stamp, 1])
 
     def append_chunk(self, pid: str, batch: R.RecordBatch,
                      idx: np.ndarray) -> None:
         self._q.append([pid, batch, idx, 0])   # mutable: [.., cursor]
         self._n += len(idx)
+        self._runs.append([perf_counter_ns(), len(idx)])
+
+    def take_stamps(self, k: int) -> int:
+        """Forget the stamps of the ``k`` records just popped; returns
+        their sum."""
+        runs = self._runs
+        total = 0
+        while k:
+            run = runs[0]
+            t = min(k, run[1])
+            total += run[0] * t
+            k -= t
+            if t == run[1]:
+                runs.popleft()
+            else:
+                run[1] -= t
+        return total
 
     def popleft(self) -> Tuple[str, int, bytes]:
         q = self._q
@@ -369,14 +399,18 @@ class LcapProxy:
             self._register_producer(pid, log)
         self.groups: Dict[str, Group] = {}
         self.consumers: Dict[str, Consumer] = {}
-        self._buffer: Deque[Tuple[str, R.RecordBatch]] = deque()
+        #: (pid, batch, perf_counter_ns when it entered); a batch cut
+        #: short by dispatch goes back with its first stamp
+        self._buffer: Deque[Tuple[str, R.RecordBatch, int]] = deque()
         self._buffered = 0                    # records currently in _buffer
         self.stats = {"ingested": 0, "dispatched": 0, "dropped_by_modules": 0,
                       "redelivered": 0, "acked_upstream": 0,
                       "ephemeral_drops": 0, "batches_ingested": 0,
                       "filtered_out": 0, "parked": 0, "resumed": 0,
                       "resume_replayed": 0, "parks_expired": 0,
-                      "replayed": 0, "tenant_filtered": 0}
+                      "replayed": 0, "tenant_filtered": 0,
+                      "dispatch_fallback_batches": 0,
+                      "dispatch_fallback_records": 0}
         #: tenant name -> TenantAccount (quota buckets + delivery
         #: counters), created lazily on first attach or set_tenant_quota
         self.tenants: Dict[str, TenantAccount] = {}
@@ -781,18 +815,21 @@ class LcapProxy:
         if len(items) > 1:
             k = next(self._ingest_rotation) % len(items)
             items = items[k:] + items[:k]
-        for pid, log in items:
-            while self._buffered < self.max_buffer:
-                batch = log.read(self.cursors[pid], self.batch_size)
-                if not batch:
-                    break
-                got = len(batch)
-                hi = batch.packed_index(got - 1)   # journal order: ascending
-                self.cursors[pid] = hi + 1
-                self._admit_locked(pid, batch, hi)
-                n += got
-                if got < self.batch_size:
-                    break
+        with TRACER.span("proxy.ingest") as span:
+            for pid, log in items:
+                while self._buffered < self.max_buffer:
+                    batch = log.read(self.cursors[pid], self.batch_size)
+                    if not batch:
+                        break
+                    got = len(batch)
+                    # journal order: ascending
+                    hi = batch.packed_index(got - 1)
+                    self.cursors[pid] = hi + 1
+                    self._admit_locked(pid, batch, hi)
+                    n += got
+                    if got < self.batch_size:
+                        break
+            span.count = n
         self.stats["ingested"] += n
         return n
 
@@ -810,7 +847,7 @@ class LcapProxy:
             kept = R.RecordBatch.from_records(kept)
         self.stats["dropped_by_modules"] += got - len(kept)
         if len(kept):
-            self._buffer.append((pid, kept))
+            self._buffer.append((pid, kept, perf_counter_ns()))
             self._buffered += len(kept)
         if hi > self.ingested.get(pid, -1):
             self.ingested[pid] = hi
@@ -821,7 +858,7 @@ class LcapProxy:
     def _hand_to(self, cons: Consumer, pid: str, idx: int, buf: bytes) -> None:
         # remote remap: strip fields the consumer did not ask for (§IV-A)
         out = R.remap_cached(buf, R.packed_flags(buf) & cons.flags)
-        cons.outbox.append((pid, idx, out))
+        cons.outbox.append((pid, idx, out), perf_counter_ns())
         cons.in_flight[(pid, idx)] = buf
         cons.delivered += 1
         if cons.account is not None:
@@ -1115,6 +1152,11 @@ class LcapProxy:
         return dispatched, filtered_out
 
     def _dispatch(self) -> int:
+        with TRACER.span("proxy.dispatch") as span:
+            span.count = n = self._dispatch_pass()
+        return n
+
+    def _dispatch_pass(self) -> int:
         n = 0
         cap = self.outbox_cap
         groups = list(self.groups.values())
@@ -1167,8 +1209,13 @@ class LcapProxy:
         filtered_out = 0
         halt = False
         quantum = self.dispatch_quantum
+        # the pass's dequeue time: records taken off the buffer waited
+        # from their batch's stamp to here (proxy.buffer_wait); the
+        # per-record path stamps its outbox entries with it too
+        t_pass = perf_counter_ns()
+        waited_ns = waited = 0
         while self._buffer:
-            pid, batch = self._buffer.popleft()
+            pid, batch, t_in = self._buffer.popleft()
             self._buffered -= len(batch)
             if self._fast_eligible(groups, ephemerals, states_sat,
                                    len(batch), n):
@@ -1176,147 +1223,163 @@ class LcapProxy:
                 dispatched += d
                 filtered_out += f
                 n += len(batch)
+                waited += len(batch)
+                waited_ns += len(batch) * (t_pass - t_in)
                 if quantum is not None and n >= quantum:
                     break
                 continue
-            # per-(batch, group) state — membership cannot change while
-            # the proxy lock is held: [group, tracker, live members,
-            # pushdown active, rtype -> eligible-members cache,
-            # saturated, tenant-scoped]
-            states = []
-            for g in groups:
-                live = [m for m in g.members.values() if m.alive]
-                states.append([g, g.tracker(pid), live,
-                               any(m.types is not None for m in live), {},
-                               states_sat[g.name],
-                               any(m.tenant is not None for m in live)])
-            need_type = any(st[3] for st in states) or \
-                any(c.types is not None for c in ephemerals)
-            pjobid = R.packed_jobid
-            packed_index = batch.packed_index
-            packed_type = batch.packed_type
-            packed = batch.packed
-            total = len(batch)
-            stop = None
-            for i in range(total):
-                idx = packed_index(i)
-                rtype = packed_type(i) if need_type else -1
-                # pushdown means a record may reach no outbox at all:
-                # materialize the packed bytes only on first real use
-                buf = None
-                jb = None          # lazily extracted jobid, shared by groups
-                for st in states:
-                    grp, tracker, live, filtered, eligible, full_g, \
-                        scoped = st
-                    tracker.deliver(idx)
-                    if not live or full_g:
-                        # no member yet, or per-group backpressure:
-                        # park for this group alone; drained on join /
-                        # recovery.  A group whose parked backlog
-                        # reaches the outbox cap halts the whole
-                        # dispatch: beyond that window the healthy
-                        # groups intentionally degrade to a trickle
-                        # (one record per pump) rather than let parked
-                        # copies grow unboundedly — operators should
-                        # fail or expire a consumer stuck that long.
-                        if buf is None:
-                            buf = packed(i)
-                        grp.pending.append((pid, idx, buf))
-                        if full_g and len(grp.pending) >= cap:
-                            halt = True
-                        continue
-                    if filtered:
-                        want = eligible.get(rtype)
-                        if want is None:
-                            want = eligible[rtype] = \
-                                [m for m in live if m.wants(rtype)]
-                        if not want:
-                            # nobody in this group asked for this op
-                            # type: acknowledged in place, never copied
-                            tracker.ack(idx)
-                            filtered_out += 1
+            # the per-record path (proxy.dispatch.fallback)
+            with TRACER.span("proxy.dispatch.fallback") as fallback:
+                # per-(batch, group) state — membership cannot change while
+                # the proxy lock is held: [group, tracker, live members,
+                # pushdown active, rtype -> eligible-members cache,
+                # saturated, tenant-scoped]
+                states = []
+                for g in groups:
+                    live = [m for m in g.members.values() if m.alive]
+                    states.append([g, g.tracker(pid), live,
+                                   any(m.types is not None for m in live), {},
+                                   states_sat[g.name],
+                                   any(m.tenant is not None for m in live)])
+                need_type = any(st[3] for st in states) or \
+                    any(c.types is not None for c in ephemerals)
+                pjobid = R.packed_jobid
+                packed_index = batch.packed_index
+                packed_type = batch.packed_type
+                packed = batch.packed
+                total = len(batch)
+                stop = None
+                for i in range(total):
+                    idx = packed_index(i)
+                    rtype = packed_type(i) if need_type else -1
+                    # pushdown means a record may reach no outbox at all:
+                    # materialize the packed bytes only on first real use
+                    buf = None
+                    jb = None    # lazily extracted jobid, shared by groups
+                    for st in states:
+                        grp, tracker, live, filtered, eligible, full_g, \
+                            scoped = st
+                        tracker.deliver(idx)
+                        if not live or full_g:
+                            # no member yet, or per-group backpressure:
+                            # park for this group alone; drained on join /
+                            # recovery.  A group whose parked backlog
+                            # reaches the outbox cap halts the whole
+                            # dispatch: beyond that window the healthy
+                            # groups intentionally degrade to a trickle
+                            # (one record per pump) rather than let parked
+                            # copies grow unboundedly — operators should
+                            # fail or expire a consumer stuck that long.
+                            if buf is None:
+                                buf = packed(i)
+                            grp.pending.append((pid, idx, buf))
+                            if full_g and len(grp.pending) >= cap:
+                                halt = True
                             continue
-                    else:
-                        want = live
-                    if scoped:
-                        # tenant pushdown, scalar flavor: out-of-scope
-                        # records are acked in place for the scoped
-                        # members, never copied
+                        if filtered:
+                            want = eligible.get(rtype)
+                            if want is None:
+                                want = eligible[rtype] = \
+                                    [m for m in live if m.wants(rtype)]
+                            if not want:
+                                # nobody in this group asked for this op
+                                # type: acknowledged in place, never copied
+                                tracker.ack(idx)
+                                filtered_out += 1
+                                continue
+                        else:
+                            want = live
+                        if scoped:
+                            # tenant pushdown, scalar flavor: out-of-scope
+                            # records are acked in place for the scoped
+                            # members, never copied
+                            if buf is None:
+                                buf = packed(i)
+                            if jb is None:
+                                jb = pjobid(buf)
+                            kept = []
+                            for m in want:
+                                if m.tenant is None or m.tenant.allows(jb):
+                                    kept.append(m)
+                                elif m.account is not None:
+                                    m.account.filtered_records += 1
+                            if not kept:
+                                tracker.ack(idx)
+                                filtered_out += 1
+                                self.stats["tenant_filtered"] += 1
+                                continue
+                            want = kept
+                        cons = want[0] if len(want) == 1 else min(want,
+                                                                  key=by_load)
                         if buf is None:
                             buf = packed(i)
-                        if jb is None:
-                            jb = pjobid(buf)
-                        kept = []
-                        for m in want:
-                            if m.tenant is None or m.tenant.allows(jb):
-                                kept.append(m)
-                            elif m.account is not None:
-                                m.account.filtered_records += 1
-                        if not kept:
-                            tracker.ack(idx)
-                            filtered_out += 1
-                            self.stats["tenant_filtered"] += 1
+                        cons.outbox.append((pid, idx, stamp(cons, buf)),
+                                           t_pass)
+                        cons.in_flight[(pid, idx)] = buf
+                        cons.delivered += 1
+                        if cons.account is not None:
+                            cons.account.charge(1, len(buf))
+                        dispatched += 1
+                        if len(cons.outbox) >= cap:
+                            st[5] = True
+                            states_sat[grp.name] = True
+                            n_sat += 1
+                            if n_sat == len(groups):
+                                halt = True   # nobody left to drain for
+                    for cons in ephemerals:
+                        if idx <= cons.since.get(pid, -1):  # type: ignore
+                            continue  # emitted before connection (§IV-B)
+                        if not cons.wants(rtype):
+                            continue  # pushdown for ephemerals: just skip
+                        if cons.tenant is not None:
+                            if buf is None:
+                                buf = packed(i)
+                            if jb is None:
+                                jb = pjobid(buf)
+                            if not cons.tenant.allows(jb):
+                                if cons.account is not None:
+                                    cons.account.filtered_records += 1
+                                continue  # out of scope: skip, like the mask
+                        if len(cons.outbox) >= cap:
+                            # radio semantics
+                            self.stats["ephemeral_drops"] += 1
                             continue
-                        want = kept
-                    cons = want[0] if len(want) == 1 else min(want,
-                                                              key=by_load)
-                    if buf is None:
-                        buf = packed(i)
-                    cons.outbox.append((pid, idx, stamp(cons, buf)))
-                    cons.in_flight[(pid, idx)] = buf
-                    cons.delivered += 1
-                    if cons.account is not None:
-                        cons.account.charge(1, len(buf))
-                    dispatched += 1
-                    if len(cons.outbox) >= cap:
-                        st[5] = True
-                        states_sat[grp.name] = True
-                        n_sat += 1
-                        if n_sat == len(groups):
-                            halt = True   # nobody left to drain for
-                for cons in ephemerals:
-                    if idx <= cons.since.get(pid, -1):  # type: ignore
-                        continue  # emitted before connection (§IV-B)
-                    if not cons.wants(rtype):
-                        continue  # pushdown for ephemerals: just skip
-                    if cons.tenant is not None:
                         if buf is None:
                             buf = packed(i)
-                        if jb is None:
-                            jb = pjobid(buf)
-                        if not cons.tenant.allows(jb):
-                            if cons.account is not None:
-                                cons.account.filtered_records += 1
-                            continue  # out of scope: skip, like the mask
-                    if len(cons.outbox) >= cap:
-                        self.stats["ephemeral_drops"] += 1   # radio semantics
-                        continue
-                    if buf is None:
-                        buf = packed(i)
-                    cons.outbox.append((pid, idx, stamp(cons, buf)))
-                    if cons.account is not None:
-                        cons.account.charge(1, len(buf))
-                n += 1
-                if halt or (quantum is not None and n >= quantum):
-                    halt = True
-                    stop = i + 1
-                    break
+                        cons.outbox.append((pid, idx, stamp(cons, buf)),
+                                           t_pass)
+                        if cons.account is not None:
+                            cons.account.charge(1, len(buf))
+                    n += 1
+                    if halt or (quantum is not None and n >= quantum):
+                        halt = True
+                        stop = i + 1
+                        break
+                done = total if stop is None else stop
+                fallback.count = done
+            self.stats["dispatch_fallback_batches"] += 1
+            self.stats["dispatch_fallback_records"] += done
+            waited += done
+            waited_ns += done * (t_pass - t_in)
             if stop is not None:
                 if stop < total:
                     # the rest of the batch goes back (a view — no copy)
                     rest = batch[stop:]
-                    self._buffer.appendleft((pid, rest))
+                    self._buffer.appendleft((pid, rest, t_in))
                     self._buffered += len(rest)
                 break
+        if waited:
+            TRACER.record("proxy.buffer_wait", t_pass - waited_ns // waited,
+                          t_pass, waited)
         self.stats["dispatched"] += dispatched
         self.stats["filtered_out"] += filtered_out
         return n
 
     def pump(self) -> int:
-        """One synchronous ingest+dispatch cycle; returns records moved."""
-        hist = self._obs_pump_hist
-        t0 = time.monotonic() if hist is not None else 0.0
-        with self._lock:
+        """One synchronous ingest+dispatch cycle; returns records moved.
+        The ``proxy.pump`` span's duration feeds the pump-latency
+        histogram when a registry is attached (and the recorder is on)."""
+        with TRACER.span("proxy.pump") as span, self._lock:
             self._expire_parked_locked()
             filtered_before = self.stats["filtered_out"]
             a = self._ingest()
@@ -1326,8 +1389,10 @@ class LcapProxy:
                 # collective watermark without any consumer commit —
                 # propagate, or a fully-filtered journal never trims
                 self._flush_upstream_locked()
-        if hist is not None and a + b:
-            hist.observe(time.monotonic() - t0)
+            span.count = a + b
+        hist = self._obs_pump_hist
+        if hist is not None and a + b and span.seconds is not None:
+            hist.observe(span.seconds)
         return a + b
 
     # ------------------------------------------------------------- replay
@@ -1352,7 +1417,7 @@ class LcapProxy:
         if start < 1:
             raise SubscriptionError(f"replay index must be >= 1 ({start})")
         buf_lo: Dict[str, int] = {}
-        for pid, batch in self._buffer:
+        for pid, batch, _ in self._buffer:
             if len(batch):
                 lo = int(batch.indices_np().min())
                 if lo < buf_lo.get(pid, lo + 1):
@@ -1528,6 +1593,7 @@ class LcapProxy:
             out = []
             while cons.outbox and len(out) < max_records:
                 out.append(cons.outbox.popleft())
+            self._outbox_waited(cons, len(out))
             return out
 
     def fetch_batches(self, cid: str, max_records: int = 1024,
@@ -1542,7 +1608,17 @@ class LcapProxy:
             cons = self._consumer(cid)
             if cons.replay_pos:
                 return []
-            return cons.outbox.pop_batches(max_records)
+            out = cons.outbox.pop_batches(max_records)
+            self._outbox_waited(cons, sum(len(b) for _, b in out))
+            return out
+
+    @staticmethod
+    def _outbox_waited(cons: Consumer, k: int) -> None:
+        """The ``proxy.outbox_wait`` span of ``k`` records just fetched."""
+        if k:
+            now = perf_counter_ns()
+            TRACER.record("proxy.outbox_wait",
+                          cons.outbox.take_stamps(k) // k, now, k)
 
     # ---------------------------------------------------------------- ack
     def ack(self, cid: str, pid: str, index: int) -> None:
@@ -1617,6 +1693,7 @@ class LcapProxy:
         base = dict(labels or {})
         names = tuple(sorted(base))
         self._obs = registry
+        TRACER.attach_registry(registry)
         self._obs_pump_hist = registry.histogram(
             "lcap_pump_latency_seconds",
             "latency of one ingest+dispatch pump cycle",

@@ -40,6 +40,7 @@ from collections import deque
 from dataclasses import dataclass, replace
 from typing import Any, Deque, Dict, Iterator, List, Optional, Tuple, Union
 
+from ..obs.spans import TRACER
 from . import records as R
 from .errors import (SessionError, SubscriptionError,  # noqa: F401 (re-export)
                      TenantError, UnknownConsumerError, raise_reply_error)
@@ -321,31 +322,35 @@ class Stream:
         """Explicitly drain up to ``max_records`` queued records; every
         returned *live* batch becomes commit-pending (replayed history
         is already acknowledged upstream).  Locally requeued batches
-        (see ``requeue``) are returned first."""
+        (see ``requeue``) are returned first.  Span ``session.fetch``
+        (records)."""
         cap = max_records or self.spec.max_records
         out, taken = [], 0
-        while self._queue and taken < cap:
-            pid, batch, from_replay = self._queue.popleft()
-            self._note(pid, batch, track=not from_replay)
-            if from_replay:
-                self.replayed += len(batch)
-            out.append((pid, batch))
-            taken += len(batch)
-        while self._replaying and taken < cap:
-            round_ = self._fetch_replay_round(cap - taken)
-            if not round_:
-                break
-            for pid, batch, _ in round_:
-                self._note(pid, batch, track=False)
-                self.replayed += len(batch)
+        with TRACER.span("session.fetch") as span:
+            while self._queue and taken < cap:
+                pid, batch, from_replay = self._queue.popleft()
+                self._note(pid, batch, track=not from_replay)
+                if from_replay:
+                    self.replayed += len(batch)
                 out.append((pid, batch))
                 taken += len(batch)
-        if taken < cap and not self._replaying:
-            for pid, batch in self.session._backend.fetch(self.cid,
-                                                          cap - taken):
-                batch = self._remap(batch)
-                self._note(pid, batch)
-                out.append((pid, batch))
+            while self._replaying and taken < cap:
+                round_ = self._fetch_replay_round(cap - taken)
+                if not round_:
+                    break
+                for pid, batch, _ in round_:
+                    self._note(pid, batch, track=False)
+                    self.replayed += len(batch)
+                    out.append((pid, batch))
+                    taken += len(batch)
+            if taken < cap and not self._replaying:
+                for pid, batch in self.session._backend.fetch(self.cid,
+                                                              cap - taken):
+                    batch = self._remap(batch)
+                    self._note(pid, batch)
+                    out.append((pid, batch))
+                    taken += len(batch)
+            span.count = taken
         return out
 
     def __iter__(self) -> Iterator[Tuple[str, R.RecordBatch]]:
@@ -403,16 +408,19 @@ class Stream:
         """Acknowledge every delivered-but-uncommitted record in one
         call; returns how many were acknowledged.  A failed commit
         keeps the records commit-pending, so a later retry still
-        acknowledges them (at-least-once)."""
+        acknowledges them (at-least-once).  Span ``session.commit``
+        (records)."""
         if not self._uncommitted:
             return 0
         acks, self._uncommitted = self._uncommitted, {}
-        try:
-            self.session._backend.commit(self.cid, acks)
-        except Exception:
-            for pid, indices in acks.items():
-                self._uncommitted.setdefault(pid, [])[:0] = indices
-            raise
+        with TRACER.span("session.commit") as span:
+            span.count = sum(len(v) for v in acks.values())
+            try:
+                self.session._backend.commit(self.cid, acks)
+            except Exception:
+                for pid, indices in acks.items():
+                    self._uncommitted.setdefault(pid, [])[:0] = indices
+                raise
         for pid, indices in acks.items():
             self.resume_token[pid] = max(self.resume_token.get(pid, 0),
                                          max(indices))

@@ -94,8 +94,9 @@ def _seed64(seq_hi, seq_lo, oid, ver):
 
 @functools.partial(jax.jit, static_argnames=("n_slots",))
 def _fid_slots_jit(seq_hi, seq_lo, oid, ver, n_slots):
-    zh, zl = _seed64(seq_hi, seq_lo, oid, ver)
-    return _mix64(zh, zl, n_slots)
+    with jax.named_scope("fid_slots"):
+        zh, zl = _seed64(seq_hi, seq_lo, oid, ver)
+        return _mix64(zh, zl, n_slots)
 
 
 def _as_pairs(seq, oid, ver):

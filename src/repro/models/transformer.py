@@ -191,12 +191,14 @@ def _slot_forward(slot_p, x, cfg: ModelConfig, i: int, positions,
                   enc_kv=None, impl="naive"):
     """One layer slot, full-sequence path.  Returns (x, aux)."""
     aux = jnp.zeros((), jnp.float32)
-    h = L.rms_norm(x, slot_p["ln1"], cfg.norm_eps)
     if cfg.layer_kind(i) == "ssm":
-        x = x + S.ssd_layer(slot_p["ssm"], h, cfg)
+        with jax.named_scope("ssd_block"):
+            h = L.rms_norm(x, slot_p["ln1"], cfg.norm_eps)
+            x = x + S.ssd_layer(slot_p["ssm"], h, cfg)
         if cfg.family == "ssm":
             return x, aux
     else:
+        h = L.rms_norm(x, slot_p["ln1"], cfg.norm_eps)
         x = x + L.attention_layer(slot_p["attn"], h, cfg, positions=positions,
                                   window=cfg.layer_window(i), impl=impl)
     if "xattn" in slot_p:
@@ -281,7 +283,8 @@ def forward(params, cfg: ModelConfig, tokens, *, frames=None,
     B, Sq = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(Sq, dtype=jnp.int32)[None],
                                  (B, Sq))
-    x = _embed(params, cfg, tokens, image_embeds)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, tokens, image_embeds)
     if cfg.sinusoidal_pos:
         x = x + L.sinusoidal_positions(Sq, cfg.d_model)[None].astype(x.dtype)
     x = lshard(x, "batch", "seq", None)
@@ -296,8 +299,9 @@ def forward(params, cfg: ModelConfig, tokens, *, frames=None,
     else:
         x, aux = _body_scan(params["body"], x, cfg, positions, impl=impl,
                             remat=remat, remat_policy=remat_policy)
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return _unembed(params, cfg, x), aux
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return _unembed(params, cfg, x), aux
 
 
 def _body_scan_encdec(params, x, cfg, positions, enc_kv, impl, remat,
@@ -322,9 +326,10 @@ def _body_scan_encdec(params, x, cfg, positions, enc_kv, impl, remat,
 # -------------------------------------------------------------------- loss
 def loss_fn(params, cfg: ModelConfig, tokens, labels, **fw_kw):
     logits, aux = forward(params, cfg, tokens, **fw_kw)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    loss = jnp.mean(lse - ll)
+    with jax.named_scope("loss"):
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        loss = jnp.mean(lse - ll)
     return loss + aux, (loss, aux)
 
 

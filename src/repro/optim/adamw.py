@@ -48,19 +48,22 @@ def clip_by_global_norm(grads, max_norm: float):
 
 def update(grads, state: AdamWState, params, *, lr, b1=0.9, b2=0.95,
            eps=1e-8, weight_decay=0.1, max_norm: float = 1.0):
-    grads, gnorm = clip_by_global_norm(grads, max_norm)
-    step = state.step + 1
-    m = jax.tree.map(lambda m_, g: b1 * m_ + (1 - b1) * g.astype(jnp.float32),
-                     state.m, grads)
-    v = jax.tree.map(lambda v_, g: b2 * v_ +
-                     (1 - b2) * jnp.square(g.astype(jnp.float32)),
-                     state.v, grads)
-    bc1 = 1 - b1 ** step.astype(jnp.float32)
-    bc2 = 1 - b2 ** step.astype(jnp.float32)
+    """One AdamW step, under the name scope ``adamw``."""
+    with jax.named_scope("adamw"):
+        grads, gnorm = clip_by_global_norm(grads, max_norm)
+        step = state.step + 1
+        m = jax.tree.map(
+            lambda m_, g: b1 * m_ + (1 - b1) * g.astype(jnp.float32),
+            state.m, grads)
+        v = jax.tree.map(lambda v_, g: b2 * v_ +
+                         (1 - b2) * jnp.square(g.astype(jnp.float32)),
+                         state.v, grads)
+        bc1 = 1 - b1 ** step.astype(jnp.float32)
+        bc2 = 1 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, m_, v_):
-        u = (m_ / bc1) / (jnp.sqrt(v_ / bc2) + eps) + weight_decay * p
-        return (p - lr * u).astype(p.dtype)
+        def upd(p, m_, v_):
+            u = (m_ / bc1) / (jnp.sqrt(v_ / bc2) + eps) + weight_decay * p
+            return (p - lr * u).astype(p.dtype)
 
-    new_params = jax.tree.map(upd, params, m, v)
+        new_params = jax.tree.map(upd, params, m, v)
     return new_params, AdamWState(step=step, m=m, v=v), gnorm

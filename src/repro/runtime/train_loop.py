@@ -22,6 +22,7 @@ from ..checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from ..core.proxy import LcapProxy
 from ..data import ShardedTokenPipeline
 from ..models import transformer as T
+from ..obs.spans import TRACER
 from ..optim import adamw
 from ..track import (ActivityTracker, CheckpointCommitter, MetricsDB,
                      StragglerDetector)
@@ -106,35 +107,56 @@ class Trainer:
 
     # ---------------------------------------------------------------- loop
     def pump_consumers(self) -> None:
-        self.proxy.pump()
-        for w in self.metrics:
-            w.poll()
-        self.committer.poll()
-        self.straggler.poll()
-        self.proxy.flush_upstream()
+        """Span ``train.pump``: the proxy, each MetricsDB worker, the
+        committer, the straggler detector, the upstream flush."""
+        with TRACER.span("train.pump"):
+            with TRACER.span("pump.proxy"):
+                self.proxy.pump()
+            for w in self.metrics:
+                with TRACER.span("pump.metrics_db"):
+                    w.poll()
+            with TRACER.span("pump.committer"):
+                self.committer.poll()
+            with TRACER.span("pump.straggler"):
+                self.straggler.poll()
+            with TRACER.span("pump.flush"):
+                self.proxy.flush_upstream()
 
     def run(self, n_steps: int) -> List[Dict[str, float]]:
+        """Take ``n_steps`` steps, each span ``train.step`` (tokens) over
+        ``train.data`` (batch assembly), ``train.launch`` (the jitted
+        step's call), ``train.wait`` (until the loss is on the host),
+        ``train.track`` (the trackers' journal writes and the checkpoint
+        submit) and ``train.pump``."""
+        tokens = self.global_batch * self.seq_len
         with use_rules(self.rules), self.mesh:
             for _ in range(n_steps):
-                t0 = time.time()
-                shards = [next(p) for p in self.pipes]
-                batch = {k: np.concatenate([s[k] for s in shards])
-                         for k in shards[0]}
-                self.params, self.opt_state, metrics = self.train_step(
-                    self.params, self.opt_state, batch)
-                loss = float(metrics["loss"])    # waits for the step
-                dt = time.time() - t0
-                self.step += 1
-                for t in self.trackers:
-                    t.step_commit(self.step, loss, dt,
-                                  self.global_batch * self.seq_len)
-                    t.heartbeat(self.step, dt)
-                if self.step % self.ckpt_every == 0:
-                    self.ckpt.submit({"params": self.params,
-                                      "opt": self.opt_state}, self.step)
-                self.pump_consumers()
-                self.history.append({"step": self.step, "loss": loss,
-                                     "time": dt})
+                with TRACER.span("train.step") as span:
+                    span.count = tokens
+                    t0 = time.time()
+                    with TRACER.span("train.data"):
+                        shards = [next(p) for p in self.pipes]
+                        batch = {k: np.concatenate([s[k] for s in shards])
+                                 for k in shards[0]}
+                    with TRACER.span("train.launch"):
+                        self.params, self.opt_state, metrics = \
+                            self.train_step(self.params, self.opt_state,
+                                            batch)
+                    with TRACER.span("train.wait"):
+                        loss = float(metrics["loss"])
+                    dt = time.time() - t0
+                    self.step += 1
+                    with TRACER.span("train.track"):
+                        for t in self.trackers:
+                            t.step_commit(self.step, loss, dt, tokens)
+                            t.heartbeat(self.step, dt)
+                        if self.step % self.ckpt_every == 0:
+                            self.ckpt.submit({"params": self.params,
+                                              "opt": self.opt_state},
+                                             self.step)
+                    self.pump_consumers()
+                    self.history.append({"step": self.step, "loss": loss,
+                                         "time": dt})
         return self.history
 
     def close(self) -> None:

@@ -13,6 +13,7 @@ from repro.core.cluster import (DEFAULT_SLOTS, LcapCluster,
 from repro.core.errors import ClusterError
 from repro.core.llog import Llog
 from repro.core.session import Subscription, connect
+from repro.obs.spans import TRACER
 
 
 def rec(oid=1, ver=0, t=R.CL_CREATE, name=b"f", **kw):
@@ -297,3 +298,74 @@ def test_cluster_stats_aggregate_across_shards():
     stats = sess.stats()
     assert stats["dispatched"] == 10       # summed across both shards
     assert set(stats["per_shard"]) == {0, 1}
+
+
+# ------------------------------------------------------- program spans
+def test_one_round_records_every_fabric_span_with_the_stats_counts():
+    """One in-process round: the journal appends, the journal wait, the
+    coordinator's round over route (over the slot kernel), offer and
+    ack, each shard's pump over ingest and dispatch, the buffer and
+    outbox waits, and the members' fetch and commit, each with the
+    records the stats count."""
+    cluster, logs = mk_cluster(n_producers=3, n_shards=2)
+    stream = connect(cluster).subscribe(Subscription(group="g",
+                                                     auto_commit=False))
+    lo = time.perf_counter()
+    t0 = time.time_ns()
+    for pid, log in logs.items():
+        log.log_batch([rec(oid=i, name=pid.encode(), time=t0)
+                       for i in range(40)])
+    cluster.pump()
+    fetched = sum(len(b) for _, b in stream.fetch(4096))
+    committed = stream.commit()
+    hi = time.perf_counter()
+
+    def sel(name):
+        return TRACER.select(name, lo, hi)
+
+    routed = cluster.stats["routed"]
+    dispatched = sum(s.proxy.stats["dispatched"] for s in cluster.shards)
+    assert routed == dispatched == fetched == committed == 120
+    assert sel("journal.append")["count"].tolist() == [40, 40, 40]
+    (wait,) = sel("journal.wait")
+    assert wait["count"] == routed
+    # the records waited from their cr_time (t0) to the route's read
+    assert 0 < wait["t1"] - wait["t0"] <= time.time_ns() - t0
+    (rnd,) = sel("cluster.round")
+    (route,) = sel("cluster.route")
+    assert rnd["count"] == route["count"] == routed
+    assert route["parent"] == rnd["seq"]
+    slots = sel("cluster.route.slots")
+    assert len(slots) == 3 and slots["count"].sum() == routed
+    assert (slots["parent"] == route["seq"]).all()
+    (offer,) = sel("cluster.offer")
+    assert offer["parent"] == route["seq"] and offer["count"] == routed
+    (ack,) = sel("cluster.ack")
+    assert ack["parent"] == rnd["seq"]
+    pumps = sel("proxy.pump")
+    assert len(pumps) == 2 and (pumps["parent"] == rnd["seq"]).all()
+    disp = sel("proxy.dispatch")
+    assert disp["count"].sum() == dispatched
+    assert set(disp["parent"].tolist()) == set(pumps["seq"].tolist())
+    assert len(sel("proxy.ingest")) == 2
+    assert len(sel("proxy.dispatch.fallback")) == 0
+    assert sel("proxy.buffer_wait")["count"].sum() == dispatched
+    assert sel("proxy.outbox_wait")["count"].sum() == fetched
+    assert sel("session.fetch")["count"].sum() == fetched
+    assert sel("session.commit")["count"].sum() == committed
+
+
+def test_round_with_the_recorder_off_delivers_and_records_nothing(
+        monkeypatch):
+    monkeypatch.setattr(TRACER, "enabled", False)
+    cluster, logs = mk_cluster(n_producers=2, n_shards=2)
+    stream = connect(cluster).subscribe(Subscription(group="g",
+                                                     auto_commit=False))
+    feed(logs, n_each=30)
+    lo = time.perf_counter()
+    expect = {(pid, i) for pid in logs for i in range(1, 31)}
+    assert drain_until(cluster, stream, logs, expect) == expect
+    monkeypatch.setattr(TRACER, "enabled", True)
+    for name in ("cluster.round", "journal.wait", "proxy.outbox_wait",
+                 "session.fetch"):
+        assert len(TRACER.select(name, lo)) == 0

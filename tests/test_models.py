@@ -3,6 +3,7 @@ forward + one train-grad step + prefill/decode consistency on CPU.
 Asserts output shapes and absence of NaNs (assignment requirement)."""
 
 import os
+import re
 import subprocess
 import sys
 
@@ -186,3 +187,35 @@ def test_init_params_same_in_every_process():
         assert r.returncode == 0, r.stderr[-3000:]
         sums.append(r.stdout.split()[-1])
     assert sums[0] == sums[1]
+
+
+# ------------------------------------------------ names on device work
+@pytest.fixture(scope="module")
+def lowered_with_debug_info():
+    """The mamba2 smoke config's train step and the routing twin,
+    lowered with their debug info (locations carry the name scopes)."""
+    from repro.kernels import stream_ops
+    from repro.optim import adamw
+    from repro.runtime.steps import TrainHParams, build_train_step
+
+    cfg = C.get_smoke("mamba2-780m")
+    params = init_params(cfg, seed=0)
+    tokens = jnp.zeros((B, S), jnp.int32)
+    step = jax.jit(build_train_step(cfg, TrainHParams(n_micro=1)))
+    z = np.zeros(8, np.uint32)
+    return {
+        "train": step.lower(params, adamw.init(params),
+                            {"tokens": tokens, "labels": tokens}
+                            ).as_text(debug_info=True),
+        "route": stream_ops._fid_slots_jit.lower(
+            z, z, z, z, n_slots=64).as_text(debug_info=True)}
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("train", "embed"), ("train", "ssd_block"), ("train", "head"),
+    ("train", "loss"), ("train", "adamw"), ("route", "fid_slots")])
+def test_named_scopes_reach_the_lowered_programs(lowered_with_debug_info,
+                                                 program, scope):
+    # "adamw/...", or "jvp(embed)/..." where the step differentiates
+    assert re.search(rf'loc\("([^"]*[/(])?{scope}[/)]',
+                     lowered_with_debug_info[program]), scope

@@ -3,12 +3,17 @@ producers, consumer groups with load balancing, broadcast across groups,
 collective upstream acknowledgement, at-least-once redelivery, ephemeral
 readers, backpressure."""
 
+import time
+
 import pytest
 
 from repro.core import records as R
 from repro.core.llog import Llog
 from repro.core.proxy import EPHEMERAL, Group, LcapProxy
 from repro.core.reader import LocalReader
+from repro.core.session import Subscription, connect
+from repro.obs import MetricsRegistry
+from repro.obs.spans import TRACER
 
 
 def rec(t=R.CL_CREATE, oid=1, name=b"f", **kw):
@@ -485,3 +490,68 @@ else:
             assert sorted(s) == sorted(expect), g  # exactly once per group
         for log in logs.values():
             assert log.first_index == log.last_index + 1   # fully trimmed
+
+
+# ------------------------------------------------- dispatch path, spans
+def _spans(name, lo):
+    return TRACER.select(name, lo)
+
+
+def test_saturated_group_takes_the_per_record_loop_and_counts_it():
+    """A batch that would overrun a member's outbox leaves the columnar
+    path: the records the per-record loop handles are what
+    ``dispatch_fallback_records`` and the ``proxy.dispatch.fallback``
+    spans count; the last batch, which fits, goes columnar again."""
+    proxy, logs = mk_proxy(1, outbox_cap=8)
+    stream = connect(proxy).subscribe(Subscription(group="g",
+                                                   auto_commit=False))
+    logs["mdt0"].log_batch([rec(oid=i) for i in range(20)])
+    lo = time.perf_counter()
+    got = 0
+    for _ in range(6):
+        proxy.pump()
+        got += sum(len(b) for _, b in stream.fetch(64))
+        stream.commit()
+    assert got == 20
+    # 8 records fill the outbox, 8 more after the fetch, the last 4 fit
+    assert proxy.stats["dispatch_fallback_batches"] == 2
+    assert proxy.stats["dispatch_fallback_records"] == 16
+    fb = _spans("proxy.dispatch.fallback", lo)
+    assert list(fb["count"]) == [8, 8]
+    assert _spans("proxy.dispatch", lo)["count"].sum() == 20
+    waits = _spans("proxy.buffer_wait", lo)
+    assert waits["count"].sum() == 20
+    assert (waits["t1"] >= waits["t0"]).all()
+    assert _spans("proxy.outbox_wait", lo)["count"].sum() == 20
+
+
+def test_columnar_dispatch_leaves_the_fallback_counters_at_zero():
+    proxy, logs = mk_proxy(2)
+    reg = MetricsRegistry()
+    proxy.attach_registry(reg)
+    stream = connect(proxy).subscribe(Subscription(group="g"))
+    feed(logs, 30)
+    lo = time.perf_counter()
+    proxy.pump()
+    assert sum(len(b) for _, b in stream.fetch(256)) == 60
+    assert proxy.stats["dispatch_fallback_batches"] == 0
+    assert proxy.stats["dispatch_fallback_records"] == 0
+    assert len(_spans("proxy.dispatch.fallback", lo)) == 0
+    (pump,) = _spans("proxy.pump", lo)
+    assert pump["count"] == 120                     # 60 in, 60 out
+    (ingest,) = _spans("proxy.ingest", lo)
+    assert ingest["count"] == 60 and ingest["parent"] == pump["seq"]
+    snap = reg.snapshot()
+    for key in ("batches", "records"):
+        (sample,) = snap[f"lcap_proxy_dispatch_fallback_{key}_total"][
+            "samples"]
+        assert sample[1] == 0
+    # the pump-latency histogram is fed from the proxy.pump span
+    (hist,) = snap["lcap_pump_latency_seconds"]["samples"]
+    assert hist[1]["count"] == 1
+    assert hist[1]["sum"] == pytest.approx(
+        (pump["t1"] - pump["t0"]) * 1e-9)
+    spans = {lb["span"] for lb, _ in
+             snap["lcap_span_records_total"]["samples"]}
+    assert {"proxy.pump", "proxy.dispatch", "proxy.buffer_wait",
+            "proxy.outbox_wait", "session.fetch"} <= spans

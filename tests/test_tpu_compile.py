@@ -38,7 +38,8 @@ def _columns(n, sharding):
 def test_fid_slots_twin_compiles(one_chip, n):
     compiled = stream_ops._fid_slots_jit.lower(
         *_columns(n, one_chip), n_slots=64).compile()
-    assert compiled.as_text()
+    # the name scope reaches the TPU program's op metadata
+    assert 'op_name="jit(_fid_slots_jit)/fid_slots/' in compiled.as_text()
 
 
 @pytest.mark.parametrize("n", [1000, 262_144, 1 << 20])

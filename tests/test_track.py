@@ -4,10 +4,14 @@ straggler detection, elastic membership, cache invalidation, index
 bootstrap."""
 
 import os
+import time
 
+from repro import configs as C
 from repro.core import records as R
 from repro.core.llog import Llog
 from repro.core.proxy import LcapProxy
+from repro.obs.spans import TRACER
+from repro.runtime.train_loop import Trainer
 from repro.track import (ActivityTracker, CacheInvalidator,
                          CheckpointCommitter, ElasticController, MetricsDB,
                          StragglerDetector, synthesize_index_stream)
@@ -316,3 +320,35 @@ def test_metrics_db_failed_close_parks_and_resumes(tmp_path):
     assert n == 10                             # only the unacked backlog
     assert w2.query("SELECT COUNT(*) FROM events")[0][0] == 20
     w2.close()
+
+
+def test_trainer_step_records_its_spans_nested(tmp_path):
+    """One ``Trainer.run`` step records train.step over its parts, and
+    train.pump over each consumer's poll, nested as they run."""
+    trainer = Trainer(C.get_smoke("mamba2-780m"), workdir=str(tmp_path),
+                      global_batch=2, seq_len=16, n_hosts=2,
+                      n_metrics_workers=2)
+    try:
+        lo = time.perf_counter()
+        trainer.run(1)
+        hi = time.perf_counter()
+    finally:
+        trainer.close()
+
+    def sel(name):
+        return TRACER.select(name, lo, hi)
+
+    (step,) = sel("train.step")
+    assert step["count"] == 2 * 16
+    for part in ("train.data", "train.launch", "train.wait", "train.track",
+                 "train.pump"):
+        (s,) = sel(part)
+        assert s["parent"] == step["seq"], part
+        assert step["t0"] <= s["t0"] <= s["t1"] <= step["t1"]
+    (pump,) = sel("train.pump")
+    assert len(sel("pump.metrics_db")) == 2
+    for part in ("pump.proxy", "pump.metrics_db", "pump.committer",
+                 "pump.straggler", "pump.flush"):
+        assert (sel(part)["parent"] == pump["seq"]).all(), part
+    (proxy_pump,) = sel("proxy.pump")
+    assert proxy_pump["parent"] == sel("pump.proxy")["seq"][0]
