@@ -144,17 +144,24 @@ def _reset_jax_probe() -> None:
     _jax_kernel = _JAX_UNRESOLVED
 
 
-def batch_slots(batch: "R.RecordBatch",
-                n_slots: int = DEFAULT_SLOTS) -> np.ndarray:
-    """Slot of every record's target FID, straight off the batch's
-    decoded header columns."""
-    seq, oid, ver = batch.tfid_cols()
+def column_slots(seq: np.ndarray, oid: np.ndarray, ver: np.ndarray,
+                 n_slots: int = DEFAULT_SLOTS) -> np.ndarray:
+    """Slot of every target FID in the columns: one call of the routing
+    kernel (the device twin where the deployment opted in), span
+    ``cluster.route.slots``."""
     kernel = _jax_fid_slots()
     with TRACER.span("cluster.route.slots") as span:
         span.count = len(seq)
         if kernel is not None and n_slots < (1 << 16):
             return kernel(seq, oid, ver, n_slots)
         return fid_slots(seq, oid, ver, n_slots)
+
+
+def batch_slots(batch: "R.RecordBatch",
+                n_slots: int = DEFAULT_SLOTS) -> np.ndarray:
+    """Slot of every record's target FID, straight off the batch's
+    decoded header columns."""
+    return column_slots(*batch.tfid_cols(), n_slots)
 
 
 class ClusterReplayReader:
@@ -434,7 +441,8 @@ class LcapCluster:
         #: parking-buffer bound: when reached, the routing loop stops
         #: reading journals (backpressure) until the migration settles
         self.park_cap = park_cap
-        self.stats = {"routed": 0, "routing_rounds": 0, "shards_failed": 0,
+        self.stats = {"routed": 0, "routing_rounds": 0, "slot_calls": 0,
+                      "shards_failed": 0,
                       "failover_redelivered": 0, "journal_acks": 0,
                       "epoch_bumps": 0, "migrations_started": 0,
                       "migrations_completed": 0, "migrations_cancelled": 0,
@@ -488,10 +496,21 @@ class LcapCluster:
                 self._migration.handoff.setdefault(pid, start - 1)
 
     # -------------------------------------------------------------- routing
-    def _partition(self, batch: R.RecordBatch) -> List[np.ndarray]:
-        """Row indices per shard, in batch (= journal) order."""
-        owner = self.routing.owner_array()[batch_slots(batch, self.n_slots)]
-        return [np.flatnonzero(owner == i) for i in range(len(self.shards))]
+    def _slots_of(self, batches: Sequence[R.RecordBatch],
+                  ) -> List[np.ndarray]:
+        """Slot of every row of each batch.  The batches' FID columns
+        are concatenated and go to the routing kernel in calls of at
+        most ``batch_size`` records: a routing round takes one or two
+        calls, not one per journal read, and every call's length stays
+        within the range one read can give (1 .. ``batch_size``)."""
+        cols = [np.concatenate(c)
+                for c in zip(*(b.tfid_cols() for b in batches))]
+        step = self.batch_size
+        parts = [column_slots(*(c[lo:lo + step] for c in cols), self.n_slots)
+                 for lo in range(0, len(cols[0]), step)]
+        self.stats["slot_calls"] += len(parts)
+        slots = np.concatenate(parts)
+        return np.split(slots, np.cumsum([len(b) for b in batches[:-1]]))
 
     def _route(self) -> Tuple[int, List[int]]:
         """One routing round: read every journal forward, partition by
@@ -522,6 +541,10 @@ class LcapCluster:
         owner_arr = self.routing.owner_array()
         drain = (self.routing.draining_mask()
                  if self._migration is not None else None)
+        # reads not yet placed: the slots of a whole round are computed
+        # together, except while slots drain, where the parking cap is
+        # checked between reads and each read is placed before the next
+        reads: List[Tuple[str, R.RecordBatch, int]] = []
         for pid, log in self.journals.items():
             while True:
                 if drain is not None and self._parked_count >= self.park_cap:
@@ -532,30 +555,18 @@ class LcapCluster:
                 got = len(batch)
                 hi = batch.packed_index(got - 1)
                 self.cursors[pid] = hi + 1
-                slots = batch_slots(batch, self.n_slots)
                 if waits:
                     age = time.time_ns() - batch.times_np().astype(np.int64)
                     aged += float(age.sum(dtype=np.float64))
-                if drain is not None and bool(drain[slots].any()):
-                    dmask = drain[slots]
-                    parked_rows = np.flatnonzero(dmask)
-                    self._parked.append((pid, batch.select(parked_rows), hi))
-                    self._parked_count += int(parked_rows.size)
-                    self.stats["parked_records"] += int(parked_rows.size)
-                    keep = np.flatnonzero(~dmask)
-                    owner = owner_arr[slots[keep]]
-                    rows = [keep[owner == i]
-                            for i in range(len(self.shards))]
-                else:
-                    owner = owner_arr[slots]
-                    rows = [np.flatnonzero(owner == i)
-                            for i in range(len(self.shards))]
-                for i, shard_rows in enumerate(rows):
-                    if self.alive[i]:
-                        offers[i].append((pid, batch.select(shard_rows), hi))
+                reads.append((pid, batch, hi))
+                if drain is not None:
+                    self._place(reads, offers, owner_arr, drain)
+                    reads = []
                 n += got
                 if got < self.batch_size:
                     break
+        if reads:
+            self._place(reads, offers, owner_arr, drain)
         if waits and n:
             now = time.perf_counter_ns()
             TRACER.record("journal.wait", now - int(aged / n), now, n)
@@ -565,6 +576,31 @@ class LcapCluster:
         self.stats["routed"] += n
         self.stats["routing_rounds"] += 1
         return n, covered
+
+    def _place(self, reads: Sequence[Tuple[str, R.RecordBatch, int]],
+               offers: List[List[Tuple[str, R.RecordBatch, int]]],
+               owner_arr: np.ndarray, drain: Optional[np.ndarray]) -> None:
+        """Append each read's rows, in journal order, to the offers of
+        the shards that own their slots; rows whose slot is draining
+        are parked instead."""
+        batches = [batch for _, batch, _ in reads]
+        for (pid, batch, hi), slots in zip(reads, self._slots_of(batches)):
+            if drain is not None and bool(drain[slots].any()):
+                dmask = drain[slots]
+                parked_rows = np.flatnonzero(dmask)
+                self._parked.append((pid, batch.select(parked_rows), hi))
+                self._parked_count += int(parked_rows.size)
+                self.stats["parked_records"] += int(parked_rows.size)
+                keep = np.flatnonzero(~dmask)
+                owner = owner_arr[slots[keep]]
+                rows = [keep[owner == i] for i in range(len(self.shards))]
+            else:
+                owner = owner_arr[slots]
+                rows = [np.flatnonzero(owner == i)
+                        for i in range(len(self.shards))]
+            for i, shard_rows in enumerate(rows):
+                if self.alive[i]:
+                    offers[i].append((pid, batch.select(shard_rows), hi))
 
     def _offer(self, offers) -> List[int]:
         """Two-phase: fire every shard's burst first, then drain the
@@ -781,7 +817,7 @@ class LcapCluster:
                 batch = log.read(lo, self.batch_size)
                 if not batch:
                     break
-                slots = batch_slots(batch, self.n_slots)
+                (slots,) = self._slots_of([batch])
                 idx = batch.indices_np().astype(np.int64)
                 keep = np.flatnonzero((idx < end) & moved_mask[slots])
                 hi = int(idx[-1])
@@ -810,7 +846,7 @@ class LcapCluster:
         offers: List[List[Tuple[str, R.RecordBatch, int]]] = \
             [[] for _ in self.shards]
         for pid, batch, hi in parked:
-            slots = batch_slots(batch, self.n_slots)
+            (slots,) = self._slots_of([batch])
             idx = batch.indices_np().astype(np.int64)
             cut = drop_above.get(pid, -1)
             keep = np.flatnonzero(~(moved_mask[slots] & (idx > cut)))

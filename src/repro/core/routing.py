@@ -71,7 +71,7 @@ class RoutingTable:
 
     def owner_array(self) -> np.ndarray:
         """``slot_owner`` as an int64 array, cached — the table is
-        immutable, so the vectorized routing paths (``_partition``,
+        immutable, so the vectorized routing paths (``_route_pass``,
         ``ClusterReplayReader``) index it without re-materializing."""
         arr = self._owner_arr
         if arr is None:

@@ -99,21 +99,41 @@ def _fid_slots_jit(seq_hi, seq_lo, oid, ver, n_slots):
         return _mix64(zh, zl, n_slots)
 
 
-def _as_pairs(seq, oid, ver):
-    seq = np.ascontiguousarray(seq, dtype=np.uint64)
-    return ((seq >> np.uint64(32)).astype(np.uint32),
-            seq.astype(np.uint32),
-            np.ascontiguousarray(oid, dtype=np.uint32),
-            np.ascontiguousarray(ver, dtype=np.uint32))
+#: smallest length ``fid_slots`` pads its columns to; above it a call
+#: pads to the next power of two, so every length up to 2^k shares one
+#: of k - 6 compiled programs
+_MIN_BUCKET = 128
+
+
+def _bucket(n: int) -> int:
+    """The padded length of an ``n``-record call."""
+    return max(_MIN_BUCKET, 1 << max(n - 1, 0).bit_length())
+
+
+def _as_pairs(seq, oid, ver, size: int = 0) -> np.ndarray:
+    """The four uint32 columns (seq hi, seq lo, oid, ver) as the rows of
+    one array, zero-padded to ``size`` rows where that is longer."""
+    seq = np.asarray(seq, dtype=np.uint64)
+    n = len(seq)
+    cols = np.zeros((4, max(n, size)), np.uint32)
+    cols[0, :n] = seq >> np.uint64(32)
+    cols[1, :n] = seq
+    cols[2, :n] = oid
+    cols[3, :n] = ver
+    return cols
 
 
 def fid_slots(seq, oid, ver, n_slots: int = 64) -> np.ndarray:
     """JAX twin of ``cluster.fid_slots``: same columns in, same slots
-    out (int64 numpy array)."""
+    out (int64 numpy array).  The columns go to the device zero-padded
+    to a bucket length (``_bucket``) and the pad rows' slots are
+    dropped on the host, so lengths 1..1024 share four programs."""
     if not 0 < n_slots < _MAX_SLOTS:
         raise ValueError(f"n_slots must be in (0, {_MAX_SLOTS})")
-    out = _fid_slots_jit(*_as_pairs(seq, oid, ver), n_slots=int(n_slots))
-    return np.asarray(out).astype(np.int64)
+    n = len(seq)
+    out = _fid_slots_jit(*_as_pairs(seq, oid, ver, _bucket(n)),
+                         n_slots=int(n_slots))
+    return np.asarray(out)[:n].astype(np.int64)
 
 
 # -- Pallas form -----------------------------------------------------------

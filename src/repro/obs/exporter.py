@@ -164,6 +164,7 @@ class GangliaPusher:
         "lcap_ingest_watermark": ("ingest_wm", "index"),
         "lcap_cluster_routed_total": ("routed", "records"),
         "lcap_cluster_failover_redelivered_total": ("refed", "records"),
+        "lcap_cluster_slot_calls_total": ("slot_calls", "calls"),
         "lcap_shard_alive": ("alive", "boolean"),
         "lcap_shard_slots_owned": ("slots", "slots"),
         "lcap_agg_records_total": ("agg_records", "records"),

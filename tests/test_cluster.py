@@ -3,13 +3,15 @@ fan-in subscriptions over every shard, collective upstream ack across
 shards, and shard failure -> slot re-routing + backlog redelivery with
 at-least-once delivery preserved."""
 
+import math
 import time
 
+import numpy as np
 import pytest
 
 from repro.core import records as R
 from repro.core.cluster import (DEFAULT_SLOTS, LcapCluster,
-                                LcapClusterService, fid_slot)
+                                LcapClusterService, fid_slot, fid_slots)
 from repro.core.errors import ClusterError
 from repro.core.llog import Llog
 from repro.core.session import Subscription, connect
@@ -335,9 +337,10 @@ def test_one_round_records_every_fabric_span_with_the_stats_counts():
     (route,) = sel("cluster.route")
     assert rnd["count"] == route["count"] == routed
     assert route["parent"] == rnd["seq"]
-    slots = sel("cluster.route.slots")
-    assert len(slots) == 3 and slots["count"].sum() == routed
-    assert (slots["parent"] == route["seq"]).all()
+    # one slot-kernel call for the round's three journal reads
+    (slots,) = sel("cluster.route.slots")
+    assert slots["count"] == routed and cluster.stats["slot_calls"] == 1
+    assert slots["parent"] == route["seq"]
     (offer,) = sel("cluster.offer")
     assert offer["parent"] == route["seq"] and offer["count"] == routed
     (ack,) = sel("cluster.ack")
@@ -369,3 +372,117 @@ def test_round_with_the_recorder_off_delivers_and_records_nothing(
     for name in ("cluster.round", "journal.wait", "proxy.outbox_wait",
                  "session.fetch"):
         assert len(TRACER.select(name, lo)) == 0
+
+
+# ------------------------------------------------------- slot-kernel calls
+@pytest.fixture(params=["twin", "numpy"])
+def slot_calls(request, monkeypatch):
+    """Every call of the routing kernel, as (length, slots): the device
+    twin, wrapped where the chip benchmark's recorder wraps it, or the
+    numpy hash."""
+    from repro.core import cluster as cluster_mod
+    if request.param == "twin":
+        from repro.kernels import stream_ops
+        monkeypatch.setenv("REPRO_JAX_ROUTING", "1")
+        module = stream_ops
+    else:
+        monkeypatch.delenv("REPRO_JAX_ROUTING", raising=False)
+        module = cluster_mod
+    calls = []
+    inner = module.fid_slots
+
+    def counted(seq, oid, ver, n_slots):
+        out = inner(seq, oid, ver, n_slots)
+        calls.append((len(seq), out))
+        return out
+
+    monkeypatch.setattr(module, "fid_slots", counted)
+    cluster_mod._reset_jax_probe()
+    yield calls
+    cluster_mod._reset_jax_probe()
+
+
+def test_a_round_over_16_journals_takes_one_slot_call_per_batch_size(
+        slot_calls, monkeypatch):
+    """The round's reads go to the kernel together, in calls of at most
+    ``batch_size`` records, and partition exactly as each journal read
+    routed alone would."""
+    cluster, logs = mk_cluster(n_producers=16, n_shards=4, batch_size=64)
+    connect(cluster).subscribe(Subscription(group="g", auto_commit=False))
+    for k, log in enumerate(logs.values()):
+        log.log_batch([rec(oid=1000 * k + i) for i in range(10 + 7 * k)])
+    # the reference: each journal read routed alone, by the numpy hash
+    owner = cluster.routing.owner_array()
+    want_slots, want_offers = [], [[] for _ in cluster.shards]
+    for pid, log in cluster.journals.items():
+        lo = cluster.cursors[pid]
+        while batch := log.read(lo, cluster.batch_size):
+            slots = fid_slots(*batch.tfid_cols(), DEFAULT_SLOTS)
+            lo = batch.packed_index(len(batch) - 1) + 1
+            want_slots.append(slots)
+            for i, shard_offers in enumerate(want_offers):
+                rows = np.flatnonzero(owner[slots] == i)
+                shard_offers.append((pid, batch.select(rows).indices(),
+                                     lo - 1))
+    offered = []
+    offer = cluster._offer
+
+    def capture(offers):
+        offered.append([[(pid, b.indices(), hi) for pid, b, hi in o]
+                        for o in offers])
+        return offer(offers)
+
+    monkeypatch.setattr(cluster, "_offer", capture)
+    cluster.pump()
+    routed = cluster.stats["routed"]
+    assert routed == sum(10 + 7 * k for k in range(16)) == 1000
+    assert len(want_slots) > 16           # some journals take two reads
+    assert [n for n, _ in slot_calls] == [64] * 15 + [40]
+    assert len(slot_calls) == math.ceil(routed / cluster.batch_size) \
+        == cluster.stats["slot_calls"]
+    np.testing.assert_array_equal(
+        np.concatenate([out for _, out in slot_calls]),
+        np.concatenate(want_slots))
+    assert offered == [want_offers]
+
+
+def test_reads_stop_at_the_park_cap_while_slots_drain(slot_calls):
+    """While a migration drains, each read is placed before the next, so
+    the round stops reading exactly where the parked rows reach the
+    cap, as when every read took its own call; the stream still ends
+    exactly once."""
+    cluster, logs = mk_cluster(n_producers=4, n_shards=2, park_cap=40,
+                               batch_size=16)
+    stream = connect(cluster).subscribe(Subscription(group="g",
+                                                     auto_commit=False))
+
+    def feed_each(lo, hi):
+        for k, log in enumerate(logs.values()):
+            log.log_batch([rec(oid=1000 * k + i) for i in range(lo, hi)])
+
+    feed_each(0, 20)
+    cluster.pump()                        # in flight, not committed
+    assert cluster.migrate_slots(cluster.routing.slots_of(0), 1)
+    assert cluster._migration is not None
+    feed_each(20, 80)
+    drain = cluster.routing.draining_mask()
+    cursors, parked, reads = dict(cluster.cursors), cluster._parked_count, 0
+    for pid, log in cluster.journals.items():
+        while parked < cluster.park_cap:
+            batch = log.read(cursors[pid], cluster.batch_size)
+            if not batch:
+                break
+            cursors[pid] = batch.packed_index(len(batch) - 1) + 1
+            parked += int(drain[fid_slots(*batch.tfid_cols(),
+                                          DEFAULT_SLOTS)].sum())
+            reads += 1
+            if len(batch) < cluster.batch_size:
+                break
+    assert any(cursors[pid] <= log.last_index for pid, log in logs.items())
+    calls0, slot_calls[:] = cluster.stats["slot_calls"], []
+    cluster._route()
+    assert cluster.cursors == cursors
+    assert cluster._parked_count == parked >= cluster.park_cap
+    assert len(slot_calls) == reads == cluster.stats["slot_calls"] - calls0
+    expect = {(pid, i) for pid in logs for i in range(1, 81)}
+    assert drain_until(cluster, stream, logs, expect) == expect

@@ -153,6 +153,34 @@ def test_tiled_pallas_fid_slots_matches_numpy(n):
     np.testing.assert_array_equal(got, fid_slots(seq, oid, ver, 97))
 
 
+@pytest.mark.parametrize("n_slots", [64, 97])
+def test_bucketed_jax_fid_slots_match_numpy_at_every_bucket_edge(n_slots):
+    """The twin pads its columns to a bucket length and drops the pad
+    rows: on either side of each edge it gives numpy's slots, in order,
+    at the input's own length."""
+    stream_ops = pytest.importorskip("repro.kernels.stream_ops")
+    rng = np.random.default_rng(n_slots)
+    for n in (1, 127, 128, 129, 1000, 1023, 1024, 1025, 5000):
+        seq = rng.integers(0, 1 << 64, n, dtype=np.uint64)
+        oid = rng.integers(0, 1 << 32, n, dtype=np.uint32)
+        ver = rng.integers(0, 1 << 32, n, dtype=np.uint32)
+        got = stream_ops.fid_slots(seq, oid, ver, n_slots)
+        assert got.dtype == np.int64 and got.shape == (n,)
+        np.testing.assert_array_equal(got, fid_slots(seq, oid, ver, n_slots))
+
+
+def test_jax_fid_slots_compile_at_most_four_programs_up_to_1024():
+    """Every length one journal read can give (1 .. 1024) runs on one
+    of four compiled programs (128, 256, 512, 1024 records)."""
+    stream_ops = pytest.importorskip("repro.kernels.stream_ops")
+    z = np.zeros(1024, np.uint64)
+    before = stream_ops._fid_slots_jit._cache_size()
+    for n in range(1, 1025):
+        stream_ops.fid_slots(z[:n], z[:n].astype(np.uint32),
+                             z[:n].astype(np.uint32), 61)
+    assert stream_ops._fid_slots_jit._cache_size() - before <= 4
+
+
 def test_jax_routing_opt_in_raises_when_twin_cannot_import(monkeypatch):
     """With REPRO_JAX_ROUTING=1 the device twin is used, and a failed
     import of it is an error, never a silent switch to numpy."""

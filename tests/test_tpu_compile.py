@@ -34,7 +34,7 @@ def _columns(n, sharding):
     return [jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=sharding)] * 4
 
 
-@pytest.mark.parametrize("n", [1024, 1 << 20])
+@pytest.mark.parametrize("n", [128, 1024, 1 << 20])
 def test_fid_slots_twin_compiles(one_chip, n):
     compiled = stream_ops._fid_slots_jit.lower(
         *_columns(n, one_chip), n_slots=64).compile()
